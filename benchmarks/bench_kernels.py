@@ -142,5 +142,7 @@ def run(report):
 
 
 if __name__ == "__main__":
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from repro.mpc.executors import enable_compile_cache
+
+    enable_compile_cache()
     run(lambda name, us, derived="": print(f"{name},{us:.1f},{derived}"))

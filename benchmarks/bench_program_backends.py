@@ -169,8 +169,7 @@ def run(report):
 
 
 if __name__ == "__main__":
-    import os
+    from repro.mpc.executors import enable_compile_cache
 
-    os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    enable_compile_cache()
     run(lambda name, us, derived="": print(f"{name},{us:.1f},{derived}"))

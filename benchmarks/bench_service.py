@@ -407,6 +407,7 @@ def run(report):
 
 
 if __name__ == "__main__":
-    os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from repro.mpc.executors import enable_compile_cache
+
+    enable_compile_cache()
     run(lambda name, us, derived="": print(f"{name},{us:.1f},{derived}"))

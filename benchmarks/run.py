@@ -72,4 +72,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.mpc.executors import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -26,7 +26,6 @@ def split_kv_decode_attention(
     k_cache: jax.Array,    # (B, S, KV, hd) — S sharded over the model axis
     v_cache: jax.Array,
 ):
-    from jax.experimental.shard_map import shard_map
 
     def body(q, k, v):
         b, h, hd = q.shape
@@ -44,12 +43,12 @@ def split_kv_decode_attention(
         out = acc / l[..., None].astype(acc.dtype)
         return out.reshape(b, h, hd)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P(None, axis_name, None, None), P(None, axis_name, None, None)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, k_cache, v_cache)
 

@@ -147,8 +147,6 @@ def replicate_to_cells(
 
 @lru_cache(maxsize=512)
 def _cp_route_fn(mesh, axis_name, spec: CPRouteSpec, cap_slot, cap_out):
-    from jax.experimental.shard_map import shard_map
-
     p = mesh.shape[axis_name]
     cp_size = math.prod(spec.dims) if spec.dims else 1
 
@@ -171,19 +169,17 @@ def _cp_route_fn(mesh, axis_name, spec: CPRouteSpec, cap_slot, cap_out):
         )
         return out[None], c[None], jnp.stack([o_s, o_o]).astype(jnp.int32)[None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis_name, None, None), P(axis_name), P(axis_name)),
         out_specs=(P(axis_name, None, None), P(axis_name), P(axis_name, None)),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
 @lru_cache(maxsize=512)
 def _hc_route_fn(mesh, axis_name, spec: HCRouteSpec, cap_slot, cap_out):
-    from jax.experimental.shard_map import shard_map
-
     p = mesh.shape[axis_name]
 
     def body(rows, cnts, salts):
@@ -203,12 +199,12 @@ def _hc_route_fn(mesh, axis_name, spec: HCRouteSpec, cap_slot, cap_out):
         )
         return out[None], c[None], jnp.stack([o_s, o_o]).astype(jnp.int32)[None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis_name, None, None), P(axis_name), P(None)),
         out_specs=(P(axis_name, None, None), P(axis_name), P(axis_name, None)),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -317,8 +313,6 @@ def batched_replicate_to_cells(
 
 @lru_cache(maxsize=512)
 def _batched_cp_route_fn(mesh, axis_name, sig: CPBatchSig, cap_slot, cap_out):
-    from jax.experimental.shard_map import shard_map
-
     p = mesh.shape[axis_name]
 
     def body(rows, cnts, offs, dims, scales, table):
@@ -334,7 +328,7 @@ def _batched_cp_route_fn(mesh, axis_name, sig: CPBatchSig, cap_slot, cap_out):
         ovf = jnp.stack([o_s.astype(jnp.int32), o_o.astype(jnp.int32)], axis=-1)
         return out[:, None], c[:, None], ovf[:, None, :]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -344,14 +338,12 @@ def _batched_cp_route_fn(mesh, axis_name, sig: CPBatchSig, cap_slot, cap_out):
         out_specs=(
             P(None, axis_name, None, None), P(None, axis_name), P(None, axis_name, None),
         ),
-        check_rep=False,
+        check_vma=False,
     ), donate_argnums=(0,))
 
 
 @lru_cache(maxsize=512)
 def _batched_hc_route_fn(mesh, axis_name, sig: HCBatchSig, cap_slot, cap_out):
-    from jax.experimental.shard_map import shard_map
-
     p = mesh.shape[axis_name]
 
     def body(rows, cnts, salts, shares, strides, table):
@@ -369,7 +361,7 @@ def _batched_hc_route_fn(mesh, axis_name, sig: HCBatchSig, cap_slot, cap_out):
         ovf = jnp.stack([o_s.astype(jnp.int32), o_o.astype(jnp.int32)], axis=-1)
         return out[:, None], c[:, None], ovf[:, None, :]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -379,7 +371,7 @@ def _batched_hc_route_fn(mesh, axis_name, sig: HCBatchSig, cap_slot, cap_out):
         out_specs=(
             P(None, axis_name, None, None), P(None, axis_name), P(None, axis_name, None),
         ),
-        check_rep=False,
+        check_vma=False,
     ), donate_argnums=(0,))
 
 
@@ -400,8 +392,6 @@ def _dest_hist(counts: jax.Array, dests: jax.Array, p: int) -> jax.Array:
 
 @lru_cache(maxsize=512)
 def _batched_cp_route_count_fn(mesh, axis_name, sig: CPBatchSig):
-    from jax.experimental.shard_map import shard_map
-
     p = mesh.shape[axis_name]
 
     def body(rows, cnts, offs, dims, scales, table):
@@ -413,7 +403,7 @@ def _batched_cp_route_count_fn(mesh, axis_name, sig: CPBatchSig):
         dests = jnp.where(table[:, None, :] < 0, -1, dests)
         return (_dest_hist(cnt, dests, p)[:, None],)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -421,14 +411,12 @@ def _batched_cp_route_count_fn(mesh, axis_name, sig: CPBatchSig):
             P(None), P(None), P(None, None),
         ),
         out_specs=(P(None, axis_name, None),),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
 @lru_cache(maxsize=512)
 def _batched_hc_route_count_fn(mesh, axis_name, sig: HCBatchSig):
-    from jax.experimental.shard_map import shard_map
-
     p = mesh.shape[axis_name]
 
     def body(rows, cnts, salts, shares, strides, table):
@@ -442,7 +430,7 @@ def _batched_hc_route_count_fn(mesh, axis_name, sig: HCBatchSig):
         dests = jnp.where(table[:, None, :] < 0, -1, dests)
         return (_dest_hist(cnt, dests, p)[:, None],)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -450,7 +438,7 @@ def _batched_hc_route_count_fn(mesh, axis_name, sig: HCBatchSig):
             P(None, None), P(None, None), P(None, None), P(None, None),
         ),
         out_specs=(P(None, axis_name, None),),
-        check_rep=False,
+        check_vma=False,
     ))
 
 

@@ -265,7 +265,6 @@ def _join_step_fn(mesh, axis_name, ka, kb, cap_slot, cap_mid, cap_out, dup_pairs
     jit's own cache handles input-shape variation, and the salt rides along as
     a traced scalar — one compiled executable serves every (H, η) stage of the
     same shape; this cache keeps repeated executor calls from re-tracing."""
-    from jax.experimental.shard_map import shard_map
 
     p = mesh.shape[axis_name]
 
@@ -278,12 +277,12 @@ def _join_step_fn(mesh, axis_name, ka, kb, cap_slot, cap_mid, cap_out, dup_pairs
         ovf = jnp.stack([s1 + s2 + m1 + m2, o3]).astype(jnp.int32)
         return out[None], cnt[None], ovf[None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis_name, None, None), P(axis_name), P(axis_name, None, None), P(axis_name), P()),
         out_specs=(P(axis_name, None, None), P(axis_name), P(axis_name, None)),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -309,8 +308,6 @@ def sharded_join_step(
 
 @lru_cache(maxsize=512)
 def _semijoin_fn(mesh, axis_name, cols, cap_slot, cap_out):
-    from jax.experimental.shard_map import shard_map
-
     p = mesh.shape[axis_name]
 
     def body(rows, cnt, offs, *pieces):
@@ -330,12 +327,12 @@ def _semijoin_fn(mesh, axis_name, cols, cap_slot, cap_out):
     piece_specs = []
     for _ in cols:
         piece_specs += [P(axis_name, None), P(axis_name)]
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis_name, None, None), P(axis_name), P(None), *piece_specs),
         out_specs=(P(axis_name, None, None), P(axis_name), P(axis_name, None)),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -364,8 +361,6 @@ def sharded_semijoin(
 
 @lru_cache(maxsize=512)
 def _intersect_fn(mesh, axis_name, n, cap_slot, cap_out):
-    from jax.experimental.shard_map import shard_map
-
     p = mesh.shape[axis_name]
 
     def body(off, *flat):
@@ -391,12 +386,12 @@ def _intersect_fn(mesh, axis_name, n, cap_slot, cap_out):
     specs = [P()]
     for _ in range(n):
         specs += [P(axis_name, None), P(axis_name)]
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body,
         mesh=mesh,
         in_specs=tuple(specs),
         out_specs=(P(axis_name, None), P(axis_name), P(axis_name, None)),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -424,8 +419,6 @@ def sharded_intersect(
 
 @lru_cache(maxsize=512)
 def _colocated_join_fn(mesh, axis_name, ka, kb, cap_out, dup_pairs):
-    from jax.experimental.shard_map import shard_map
-
     def body(a_rows, a_cnt, b_rows, b_cnt):
         out, cnt, ovf = local_join_filtered(
             a_rows[0], a_cnt[0], b_rows[0], b_cnt[0], ka, kb, cap_out, dup_pairs
@@ -435,12 +428,12 @@ def _colocated_join_fn(mesh, axis_name, ka, kb, cap_out, dup_pairs):
             [jnp.zeros((), jnp.int32), ovf.astype(jnp.int32)]
         )[None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis_name, None, None), P(axis_name), P(axis_name, None, None), P(axis_name)),
         out_specs=(P(axis_name, None, None), P(axis_name), P(axis_name, None)),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -481,8 +474,6 @@ def sharded_colocated_join(
 
 @lru_cache(maxsize=512)
 def _batched_intersect_fn(mesh, axis_name, n, cap_slot, cap_out):
-    from jax.experimental.shard_map import shard_map
-
     p = mesh.shape[axis_name]
 
     def body(offs, *flat):
@@ -512,12 +503,12 @@ def _batched_intersect_fn(mesh, axis_name, n, cap_slot, cap_out):
     specs = [P(None)]
     for _ in range(n):
         specs += [P(None, axis_name, None), P(None, axis_name)]
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body,
         mesh=mesh,
         in_specs=tuple(specs),
         out_specs=(P(None, axis_name, None), P(None, axis_name), P(None, axis_name, None)),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -545,8 +536,6 @@ def batched_sharded_intersect(
 
 @lru_cache(maxsize=512)
 def _batched_semijoin_fn(mesh, axis_name, col, cap_slot, cap_out):
-    from jax.experimental.shard_map import shard_map
-
     p = mesh.shape[axis_name]
 
     def body(rows, cnt, offs, pv, pc):
@@ -561,7 +550,7 @@ def _batched_semijoin_fn(mesh, axis_name, col, cap_slot, cap_out):
         ovf = jnp.stack([o_s.astype(jnp.int32), o_o.astype(jnp.int32)], axis=-1)
         return rows[:, None], cnt[:, None], ovf[:, None, :]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -571,7 +560,7 @@ def _batched_semijoin_fn(mesh, axis_name, col, cap_slot, cap_out):
         out_specs=(
             P(None, axis_name, None, None), P(None, axis_name), P(None, axis_name, None),
         ),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -598,8 +587,6 @@ def batched_sharded_semijoin(
 
 @lru_cache(maxsize=512)
 def _batched_colocated_join_fn(mesh, axis_name, ka, kb, cap_out, dup_pairs, packed):
-    from jax.experimental.shard_map import shard_map
-
     def body(a_rows, a_cnt, b_rows, b_cnt, mults):
         # mults (s, ndup) replicated; packed is static, so the unpacked variant
         # traces no use of it (it rides along as a zero-size dummy)
@@ -617,7 +604,7 @@ def _batched_colocated_join_fn(mesh, axis_name, ka, kb, cap_out, dup_pairs, pack
     # the stacked input blocks are rebuilt host-side per dispatch, so their
     # device copies are single-use: donating them lets XLA reuse the pages
     # for the (equally large) expansion buffers
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -627,7 +614,7 @@ def _batched_colocated_join_fn(mesh, axis_name, ka, kb, cap_out, dup_pairs, pack
         out_specs=(
             P(None, axis_name, None, None), P(None, axis_name), P(None, axis_name, None),
         ),
-        check_rep=False,
+        check_vma=False,
     ), donate_argnums=(0, 2))
 
 
@@ -662,8 +649,6 @@ def batched_sharded_colocated_join(
 
 @lru_cache(maxsize=512)
 def _batched_colocated_count_fn(mesh, axis_name, ka, kb, dup_pairs, packed):
-    from jax.experimental.shard_map import shard_map
-
     def body(a_rows, a_cnt, b_rows, b_cnt, mults):
         cnt = jax.vmap(
             lambda ar, ac, br, bc, m: local_join_count(
@@ -674,7 +659,7 @@ def _batched_colocated_count_fn(mesh, axis_name, ka, kb, dup_pairs, packed):
         s = cnt.shape[0]
         return cnt[:, None], jnp.zeros((s, 1, 2), jnp.int32)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -682,7 +667,7 @@ def _batched_colocated_count_fn(mesh, axis_name, ka, kb, dup_pairs, packed):
             P(None, axis_name, None, None), P(None, axis_name), P(None, None),
         ),
         out_specs=(P(None, axis_name), P(None, axis_name, None)),
-        check_rep=False,
+        check_vma=False,
     ))
 
 

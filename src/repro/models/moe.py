@@ -191,9 +191,8 @@ def _moe_a2a(cfg, p, x_flat, axes):
         )
         return out
 
-    from jax.experimental.shard_map import shard_map
 
-    body_sm = shard_map(
+    body_sm = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -205,7 +204,7 @@ def _moe_a2a(cfg, p, x_flat, axes):
             P(tp, None, None),
         ),
         out_specs=P(tok_spec, None),
-        check_rep=False,
+        check_vma=False,
     )
     out = body_sm(x_flat, topk_idx, topk_w, p["w_gate"], p["w_up"], p["w_out"])
     return out, aux

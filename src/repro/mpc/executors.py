@@ -24,11 +24,13 @@ from __future__ import annotations
 
 import hashlib
 import math
+import os
 import time
 import warnings
 from collections import defaultdict
 from dataclasses import dataclass, field
 from functools import partial
+from pathlib import Path
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -808,6 +810,9 @@ class DataplaneJoinResult:
     #: under "<round>/count".  Routing rounds ≈ argsort/rank-key + all_to_all;
     #: "output" rounds ≈ the local merge-join kernels.
     round_us: Dict[str, float] = field(default_factory=dict)
+    #: rows each device holds after each routing round (GridRoute/ShareRoute),
+    #: summed over the round's stages — how the data spreads over the mesh.
+    device_rows: Dict[str, List[int]] = field(default_factory=dict)
 
 
 class DataplaneUnsupported(NotImplementedError):
@@ -868,11 +873,40 @@ class ExecutableCache:
     def clear(self) -> None:
         self._entries.clear()
 
+    def values(self) -> list:
+        """The cached executables, least recently used first."""
+        return list(self._entries.values())
+
 
 #: default process-wide executable cache shared by every DataplaneExecutor —
 #: the jit half of the service layer's warm path (a JoinSession's repeat
 #: queries hit it even across executor instances).
 EXECUTABLE_CACHE = ExecutableCache(capacity=1024)
+
+
+#: where JAX's persistent compilation cache lives when ``JAX_COMPILATION_CACHE_DIR``
+#: is unset: a fixed path at the repository root (the directory is part of what
+#: a later process looks up, so it never depends on a temp name, PID or time).
+DEFAULT_COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point (a script's
+    ``__main__``) and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself and no other
+    directory is set; otherwise the cache goes to :data:`DEFAULT_COMPILE_CACHE_DIR`.
+    Every executable is cached however quickly it compiled: a cold submit
+    compiles many small per-bucket executables, most below JAX's default
+    one-second floor."""
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(DEFAULT_COMPILE_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return path
 
 
 def _salt(*key, attempt: int = 0) -> int:
@@ -960,6 +994,9 @@ class BatchRunStats:
     bucket_stage_counts: Dict[str, List[int]] = field(default_factory=dict)
     phase_us: Dict[str, float] = field(default_factory=dict)
     round_us: Dict[str, float] = field(default_factory=dict)
+    #: rows each device holds after each routing round (GridRoute/ShareRoute),
+    #: summed over the round's stages — how the data spreads over the mesh.
+    device_rows: Dict[str, List[int]] = field(default_factory=dict)
 
 
 @dataclass
@@ -1186,6 +1223,7 @@ class DataplaneExecutor:
         self.caps_quarantined = 0
         self._phase_us: Dict[str, float] = {}
         self._round_us: Dict[str, float] = {}
+        self._device_rows: Dict[str, np.ndarray] = {}
 
     # -- capacity guesses (pow2-bucketed so retries and repeat runs hit the
     # -- jit cache; all of them are starting points for the doubling retry) ---
@@ -1281,6 +1319,7 @@ class DataplaneExecutor:
         self._bucket_log: Dict[str, List[int]] = {}
         self._phase_us = {"host_prep": 0.0, "compile": 0.0, "launch": 0.0, "sync": 0.0}
         self._round_us = {}
+        self._device_rows = {}
         self._deadline = config.deadline if config is not None else None
         self._fault_plan_run = (
             config.fault_plan if config is not None and config.fault_plan is not None
@@ -1334,6 +1373,7 @@ class DataplaneExecutor:
             bucket_stage_counts={k: list(v) for k, v in self._bucket_log.items()},
             phase_us=dict(self._phase_us),
             round_us=dict(self._round_us),
+            device_rows={k: v.tolist() for k, v in self._device_rows.items()},
         )
         results: List[DataplaneJoinResult] = []
         for qi, program in enumerate(programs):
@@ -1375,6 +1415,7 @@ class DataplaneExecutor:
                 },
                 phase_us=dict(batch.phase_us),
                 round_us=dict(batch.round_us),
+                device_rows={k: list(v) for k, v in batch.device_rows.items()},
             ))
         return results, batch
 
@@ -1616,7 +1657,6 @@ class DataplaneExecutor:
 
                 todo = list(to_compile.items())
                 if len(todo) > 1:
-                    import os
                     from concurrent.futures import ThreadPoolExecutor
 
                     workers = min(len(todo), max(2, os.cpu_count() or 2))
@@ -2231,11 +2271,16 @@ class DataplaneExecutor:
         for it in self._run_buckets(op.round, items, make_dispatch(count=False)):
             rows, cnts = it.result
             n = int(cnts.sum())
+            self._note_device_rows(op.round, cnts)
             if it.key[0] == "hc":
                 scheme = ["#cell"] + list(it.payload["scheme"])
             else:
                 scheme = ["#cell", it.payload["x"]]
             it.state.routed[it.payload["pos"]] = (scheme, rows, cnts, n)
+
+    def _note_device_rows(self, round_name: str, cnts) -> None:
+        rows = np.asarray(cnts, np.int64)
+        self._device_rows[round_name] = self._device_rows.get(round_name, 0) + rows
 
     def _make_colocated_dispatch(self, count: bool):
         """Bucket dispatch for one level of in-cell colocated joins — shared
@@ -2693,6 +2738,7 @@ class DataplaneExecutor:
         for it in self._run_buckets(op.round, items, make_dispatch(count=False)):
             rows, cnts = it.result
             n = int(cnts.sum())
+            self._note_device_rows(op.round, cnts)
             scheme = ["#cell"] + list(it.payload["scheme"])
             it.state.routed[it.payload["pos"]] = (scheme, rows, cnts, n)
 

@@ -52,7 +52,6 @@ def _hier_one(g: jax.Array, data_size: int) -> jax.Array:
 def hierarchical_mean(grads: Any, mesh, replicated_specs) -> Any:
     """All leaves are replicated inputs per (pod, data) and already divided by the
     global batch; returns the cross-replica mean with the hierarchical schedule."""
-    from jax.experimental.shard_map import shard_map
 
     n_rep = mesh.shape["pod"] * mesh.shape["data"]
     data_size = mesh.shape["data"]
@@ -60,9 +59,9 @@ def hierarchical_mean(grads: Any, mesh, replicated_specs) -> Any:
     def body(g):
         return jax.tree.map(lambda x: _hier_one(x, data_size) / n_rep, g)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(replicated_specs,), out_specs=replicated_specs,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(grads)

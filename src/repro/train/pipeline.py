@@ -33,7 +33,6 @@ def pipelined_forward(
 
     stage_fn(x_micro, params_slice) applies one stage's layers.
     """
-    from jax.experimental.shard_map import shard_map
 
     def body(xm, sp):
         # xm: (n_micro, B, ...) replicated per stage; sp: this stage's params (1, ...)
@@ -75,10 +74,10 @@ def pipelined_forward(
         )
         return outs
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(stage_axis)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, stage_params)

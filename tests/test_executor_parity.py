@@ -440,3 +440,34 @@ def test_compile_count_scales_with_buckets_not_stages():
     hist = program.bucket_histogram()
     assert sum(hist.values()) == len(program.stages)
     assert len(hist) < len(program.stages)
+
+
+@pytest.mark.parametrize("from_env", [False, True], ids=["default-dir", "env-dir"])
+def test_enable_compile_cache_directory(tmp_path, from_env):
+    """Entry points keep JAX's persistent cache in JAX_COMPILATION_CACHE_DIR
+    when it is set (and set no other directory), else at the fixed
+    <repo>/.jax_cache, caching every executable however fast it compiled."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    repo = Path(__file__).resolve().parents[1]
+    code = (
+        "import jax\n"
+        "from repro.mpc.executors import enable_compile_cache\n"
+        "print(enable_compile_cache())\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+        "print(jax.config.jax_persistent_cache_min_compile_time_secs)\n"
+    )
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = os.pathsep.join(filter(None, [str(repo / "src"), env.get("PYTHONPATH")]))
+    want = str(repo / ".jax_cache")
+    if from_env:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True,
+                         env=env, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    path, configured, min_secs = out.stdout.split("\n")[:3]
+    assert path == configured == want
+    assert float(min_secs) == 0

@@ -29,7 +29,11 @@ from repro.models.mamba import ssd_reference
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("n,m", [(256, 1024), (512, 2048), (300, 1500), (256, 999)])
+@pytest.mark.parametrize("n,m", [
+    (256, 1024), (512, 2048), (300, 1500), (256, 999),
+    # several 1024-key blocks on each side, neither a multiple of the block
+    (2049, 1025), (1500, 3100),
+])
 @pytest.mark.parametrize("dom", [50, 10_000])
 def test_merge_join_counts_matches_searchsorted(n, m, dom):
     rng = np.random.default_rng(n + m + dom)
@@ -46,7 +50,7 @@ if HAVE_HYPOTHESIS:
     @settings(max_examples=15, deadline=None)
     @given(
         seed=st.integers(0, 1_000),
-        n=st.integers(1, 700),
+        n=st.integers(1, 2500),
         m=st.integers(1, 3000),
         dom=st.integers(1, 500),
     )
@@ -94,6 +98,7 @@ def _pairs_fixture(seed, n, m, dom, cap_out):
     (300, 1500, 40, 1 << 12),
     (512, 2048, 10_000, 1 << 10),
     (1, 7, 3, 64),
+    (1100, 2500, 60, 3000),     # 2 key blocks × 3 output blocks, ragged
 ])
 def test_merge_join_pairs_matches_ref_and_expansion(n, m, dom, cap_out):
     lower, starts, total, exp_a, exp_b = _pairs_fixture(n + m + dom, n, m, dom, cap_out)
@@ -160,8 +165,8 @@ def test_merge_join_total_pairs_vs_join():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("n", [1024, 4096, 1000])
-@pytest.mark.parametrize("parts", [8, 64, 256])
+@pytest.mark.parametrize("n", [1024, 4096, 1000, 2500])
+@pytest.mark.parametrize("parts", [1, 4, 16, 8, 64, 256])
 def test_hash_partition_matches_ref(n, parts):
     rng = np.random.default_rng(n * parts)
     keys = rng.integers(-(2**62), 2**62, n).astype(np.int64)
@@ -193,7 +198,11 @@ def _pack_check(keys, count, parts):
     return part, slot, send
 
 
-@pytest.mark.parametrize("n,parts", [(1024, 8), (4096, 64), (1000, 16)])
+@pytest.mark.parametrize("n,parts", [
+    (1024, 8), (4096, 64), (1000, 16),
+    # ragged multi-tile lists: the running base crosses tile boundaries
+    (2500, 1), (3000, 4), (5000, 16),
+])
 @pytest.mark.parametrize("frac", [1.0, 0.7])
 def test_hash_partition_pack_matches_ref(n, parts, frac):
     rng = np.random.default_rng(n * parts)
@@ -212,7 +221,7 @@ if HAVE_HYPOTHESIS:
     @given(
         seed=st.integers(0, 1_000),
         n=st.integers(1, 2000),
-        parts=st.sampled_from([2, 8, 32, 128]),
+        parts=st.sampled_from([1, 2, 4, 8, 16, 32, 128]),
         frac=st.floats(0.0, 1.0),
     )
     def test_hash_partition_pack_property(seed, n, parts, frac):
@@ -239,6 +248,95 @@ def test_hash_partition_balanced():
     _, hist = hash_partition(jnp.asarray(keys), 16)
     h = np.asarray(hist)
     assert h.max() < 2.0 * h.mean()
+
+
+# ---------------------------------------------------------------------------
+# stage batching and platform choice
+# ---------------------------------------------------------------------------
+
+
+def _sorted_batch(rng, shape, dom):
+    return jnp.asarray(np.sort(rng.integers(0, dom, shape), axis=-1).astype(np.int32))
+
+
+def _batched_cases():
+    rng = np.random.default_rng(5)
+    a, b = _sorted_batch(rng, (3, 1500), 60), _sorted_batch(rng, (3, 2100), 60)
+    counts = rng.integers(0, 3, (3, 1100)).astype(np.int32)
+    starts = jnp.asarray(np.cumsum(counts, axis=1) - counts)
+    lower = jnp.asarray(rng.integers(0, 100, (3, 1100)).astype(np.int32))
+    keys = jnp.asarray(rng.integers(0, 2**30, (3, 2500)).astype(np.int32))
+    valid = jnp.asarray([2500, 7, 1800], jnp.int32)
+    cases = [
+        ("merge_join_counts", lambda up: jax.vmap(
+            lambda x, y: merge_join_counts(x, y, use_pallas=up))(a, b)),
+        ("merge_join_pairs", lambda up: jax.vmap(
+            lambda lo, st: merge_join_pairs(lo, st, 2500, use_pallas=up))(lower, starts)),
+        # vmap of vmap: both mapped axes fold into the kernel's problem axis
+        ("merge_join_counts-nested", lambda up: jax.vmap(jax.vmap(
+            lambda x, y: merge_join_counts(x, y, use_pallas=up)))(a[None], b[None])),
+    ]
+    for parts in (1, 4, 16):
+        cases += [
+            (f"hash_partition-p{parts}", lambda up, parts=parts: jax.vmap(
+                lambda k: hash_partition(k, parts, use_pallas=up))(keys)),
+            (f"hash_partition_pack-p{parts}", lambda up, parts=parts: jax.vmap(
+                lambda k, c: hash_partition_pack(k, c, parts, use_pallas=up))(keys, valid)),
+        ]
+    # an unbatched operand broadcasts across the mapped axis
+    cases.append(("merge_join_counts-broadcast", lambda up: jax.vmap(
+        lambda x: merge_join_counts(x, b[0], use_pallas=up))(a)))
+    return cases
+
+
+BATCHED_CASES = _batched_cases()
+
+
+@pytest.mark.parametrize("name,run", BATCHED_CASES, ids=[c[0] for c in BATCHED_CASES])
+def test_vmapped_kernels_match_ref(name, run):
+    """The dataplane vmaps every kernel over a bucket's stages; under vmap the
+    kernels fold the mapped axis into their problem axis (one grid row per
+    problem) and must stay bit-identical to the vmapped jnp reference."""
+    for k, r in zip(run(True), run(False)):
+        np.testing.assert_array_equal(np.asarray(k), np.asarray(r))
+
+
+def test_kernel_choice_follows_platform(monkeypatch):
+    from repro.kernels import ops
+
+    assert ops.probe_use_pallas() is (jax.default_backend() == "tpu")
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    assert ops.probe_use_pallas() is True
+    monkeypatch.setattr(ops, "on_tpu", lambda: False)
+    assert ops.probe_use_pallas() is False
+
+
+def test_importing_repro_starts_no_backend():
+    """The kernel choice is made when a kernel is traced: importing the
+    package (every module of the join engine) must not start a JAX backend,
+    which on a TPU host would take the chip."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+    from pathlib import Path
+
+    code = textwrap.dedent("""
+        import importlib, pkgutil
+        import repro
+        from jax._src import xla_bridge
+        for m in pkgutil.walk_packages(repro.__path__, "repro."):
+            if m.name.startswith("repro.launch"):
+                continue  # the LM launcher configures XLA flags as it imports
+            importlib.import_module(m.name)
+        assert not xla_bridge.backends_are_initialized(), "a backend started"
+    """)
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    env = {**os.environ, "PYTHONPATH": os.pathsep.join(
+        filter(None, [src, os.environ.get("PYTHONPATH")]))}
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True,
+                         env=env, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
 
 
 # ---------------------------------------------------------------------------
