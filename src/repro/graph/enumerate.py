@@ -29,6 +29,7 @@ import numpy as np
 from ..core.hypergraph import fractional_edge_cover
 from ..core.planner import heavy_parameter
 from ..core.taxonomy import compute_stats
+from ..obs import span
 from .compile import CompiledPattern, compile_pattern
 from .graphs import Graph
 from .patterns import Pattern, automorphisms, canonical_rows
@@ -41,7 +42,10 @@ class EnumerationResult:
     ``occurrences``: (count, k) int64, row = G-vertices bound to pattern
     vertices 0..k-1, canonicalized (lex-min automorphic image) and sorted.
     ``embeddings``: raw Join(Q) rows before injectivity/dedup — the
-    homomorphism count the engine actually materialized."""
+    homomorphism count the engine actually materialized.
+    ``host_us``: host time of the graph layer around the join — the pattern's
+    compilation and the post-processing (the ``graph.compile_pattern`` and
+    ``graph.postprocess`` spans)."""
 
     pattern: Pattern
     backend: str
@@ -50,6 +54,7 @@ class EnumerationResult:
     embeddings: int
     compiled: CompiledPattern
     engine: object
+    host_us: float = 0.0
 
 
 def postprocess_rows(compiled: CompiledPattern, rows: np.ndarray) -> np.ndarray:
@@ -108,40 +113,45 @@ def enumerate_subgraphs(
         An :class:`EnumerationResult`: exactly-once ``occurrences`` plus the
         engine run behind them.
     """
-    compiled = compile_pattern(graph, pattern, orientation)
-    q = compiled.query
-    if session is not None:
-        p, backend = session.p, session.backend    # the session's plans rule
-    if lam is None:
-        rho_val = float(fractional_edge_cover(q.hypergraph)[0])
-        lam = heavy_parameter(p, rho_val)
+    with span("graph.enumerate", pattern=pattern.name):
+        with span("graph.compile_pattern") as compile_span:
+            compiled = compile_pattern(graph, pattern, orientation)
+        q = compiled.query
+        if session is not None:
+            p, backend = session.p, session.backend    # the session's plans rule
+        if lam is None:
+            rho_val = float(fractional_edge_cover(q.hypergraph)[0])
+            lam = heavy_parameter(p, rho_val)
 
-    if session is not None:
-        res = session.submit(q, lam=lam, fuse_semijoin=fuse_semijoin).result
-    elif backend == "simulator":
-        from ..mpc.engine import mpc_join
+        if session is not None:
+            res = session.submit(q, lam=lam, fuse_semijoin=fuse_semijoin).result
+        elif backend == "simulator":
+            from ..mpc.engine import mpc_join
 
-        res = mpc_join(q, p=p, seed=seed, lam=lam, fuse_semijoin=fuse_semijoin)
-    elif backend == "dataplane":
-        from ..mpc.executors import DataplaneExecutor
-        from ..mpc.program import compile_plan, fuse_semijoin_pass
+            res = mpc_join(q, p=p, seed=seed, lam=lam, fuse_semijoin=fuse_semijoin)
+        elif backend == "dataplane":
+            from ..mpc.executors import DataplaneExecutor
+            from ..mpc.program import compile_plan, fuse_semijoin_pass
 
-        stats = compute_stats(q, lam)
-        program = compile_plan(q, stats, p)
-        if fuse_semijoin:
-            program = fuse_semijoin_pass(program)
-        ex = executor if executor is not None else DataplaneExecutor()
-        res = ex.run(program)
-    else:
-        raise ValueError(f"unknown backend {backend!r}")
+            stats = compute_stats(q, lam)
+            program = compile_plan(q, stats, p)
+            if fuse_semijoin:
+                program = fuse_semijoin_pass(program)
+            ex = executor if executor is not None else DataplaneExecutor()
+            res = ex.run(program)
+        else:
+            raise ValueError(f"unknown backend {backend!r}")
 
-    occ = postprocess_rows(compiled, res.rows)
-    return EnumerationResult(
-        pattern=pattern,
-        backend=backend,
-        occurrences=occ,
-        count=int(occ.shape[0]),
-        embeddings=int(res.count),
-        compiled=compiled,
-        engine=res,
-    )
+        with span("graph.postprocess") as post_span:
+            occ = postprocess_rows(compiled, res.rows)
+            post_span.set(rows=int(occ.shape[0]))
+        return EnumerationResult(
+            pattern=pattern,
+            backend=backend,
+            occurrences=occ,
+            count=int(occ.shape[0]),
+            embeddings=int(res.count),
+            compiled=compiled,
+            engine=res,
+            host_us=compile_span.us + post_span.us,
+        )

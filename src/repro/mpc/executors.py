@@ -44,6 +44,7 @@ warnings.filterwarnings(
 )
 
 from ..core.query import Attr, JoinQuery, Relation, reference_join
+from ..obs import span
 from ..core.taxonomy import heavy_masks, residual_relations
 from .faults import DeadlineExceededError, RetryExhaustedError
 from .hypercube import HyperCubeGrid, route_hypercube
@@ -799,20 +800,31 @@ class DataplaneJoinResult:
     caps_misses: int = 0
     caps_evictions: int = 0
     bucket_stage_counts: Dict[str, List[int]] = field(default_factory=dict)
-    #: coarse per-phase wall time (µs) across the whole run: "host_prep"
-    #: (dispatch building: host stacking + staging), "compile" (AOT
-    #: trace+compile of cache misses), "launch" (dispatching executables;
-    #: async — device work overlaps the schedule), "sync" (the one deferred
-    #: device→host readback per bucket — where collective+kernel time
-    #: actually surfaces on the host clock).
+    #: coarse per-phase wall time (µs) across the whole run, summed from the
+    #: ``executor.<phase>`` spans: "host_prep" (dispatch building: host
+    #: stacking + staging), "compile" (AOT trace+compile of cache misses),
+    #: "launch" (dispatching executables; async — device work overlaps the
+    #: schedule), "sync" (the one deferred device→host readback per bucket —
+    #: where collective+kernel time actually surfaces on the host clock).
     phase_us: Dict[str, float] = field(default_factory=dict)
-    #: per-round wall time (µs), keyed by op round name — count rounds appear
-    #: under "<round>/count".  Routing rounds ≈ argsort/rank-key + all_to_all;
-    #: "output" rounds ≈ the local merge-join kernels.
+    #: per-round wall time (µs) of the ``executor.round`` spans, keyed by op
+    #: round name — count rounds appear under "<round>/count".  Routing
+    #: rounds ≈ argsort/rank-key + all_to_all; "output" rounds ≈ the local
+    #: merge-join kernels.
     round_us: Dict[str, float] = field(default_factory=dict)
     #: rows each device holds after each routing round (GridRoute/ShareRoute),
     #: summed over the round's stages — how the data spreads over the mesh.
     device_rows: Dict[str, List[int]] = field(default_factory=dict)
+    #: host→device bytes the launches shipped (the host operands' ``nbytes``),
+    #: device→host bytes the syncs pulled and the number of arrays they pulled
+    #: (each a blocking read), all from shapes alone.
+    h2d_bytes: int = 0
+    d2h_bytes: int = 0
+    host_syncs: int = 0
+    #: host time (µs) of the lowering rules outside their op rounds (blocking,
+    #: key packing, unblocking) plus result assembly: ``executor.op`` spans
+    #: less their ``executor.round`` children, plus ``executor.assemble``.
+    lowering_us: float = 0.0
 
 
 class DataplaneUnsupported(NotImplementedError):
@@ -937,6 +949,23 @@ def _quant(n: int) -> int:
     return p2
 
 
+def _host_nbytes(args) -> int:
+    """Bytes a launch ships to the device: the host (numpy) operands among
+    ``args``; device-resident operands move nothing."""
+    return sum(a.nbytes for a in args if isinstance(a, np.ndarray))
+
+
+def _pulled(outs, ovf) -> Tuple[int, int]:
+    """(bytes, arrays) a bucket's sync pulls to the host, from shapes alone.
+
+    ``finalize`` reads every output of the dispatch but the overflow channel,
+    which the overflow read pulls as the slice ``ovf``; a host ``ovf`` means
+    the primitive has no overflow output, and every output is ``finalize``'s."""
+    arrays = outs if isinstance(ovf, np.ndarray) else (*outs[:-1], ovf)
+    arrays = [a for a in arrays if not isinstance(a, np.ndarray)]
+    return sum(int(a.size) * a.dtype.itemsize for a in arrays), len(arrays)
+
+
 def _pack_radices(a_blocks, b_blocks, dup_pairs) -> Optional[np.ndarray]:
     """Host-side eligibility check for packed int32 composite join keys.
 
@@ -997,6 +1026,10 @@ class BatchRunStats:
     #: rows each device holds after each routing round (GridRoute/ShareRoute),
     #: summed over the round's stages — how the data spreads over the mesh.
     device_rows: Dict[str, List[int]] = field(default_factory=dict)
+    h2d_bytes: int = 0
+    d2h_bytes: int = 0
+    host_syncs: int = 0
+    lowering_us: float = 0.0
 
 
 @dataclass
@@ -1148,6 +1181,11 @@ class DataplaneExecutor:
     _tainted_caps: Optional[set] = None     # keys that saw injected overflow
     _caps_quarantined = 0                   # per-run quarantine count
     _run_fps: Tuple[str, ...] = ()          # per-program data fingerprints
+    # per-run transfer counters and lowering host time (see DataplaneJoinResult)
+    _h2d_bytes = 0
+    _d2h_bytes = 0
+    _host_syncs = 0
+    _lowering_us = 0.0
 
     def __init__(
         self,
@@ -1286,11 +1324,21 @@ class DataplaneExecutor:
         per-run ``fault_plan`` override.  On ANY failure the run's touched
         learned-caps entries are quarantined (dropped from the store) before
         the exception propagates, so a faulted attempt cannot poison the
-        zero-retry steady state of later clean runs."""
+        zero-retry steady state of later clean runs.
+
+        Host spans (:mod:`repro.obs`): ``executor.run`` around the run, an
+        ``executor.op`` per lowering rule, an ``executor.round`` per op round
+        with its ``executor.host_prep``/``compile``/``launch``/``sync``
+        phases, and ``executor.assemble`` around the result assembly."""
         if config is not None:
             materialize = config.materialize
         if not programs:
             return [], BatchRunStats(queries=0)
+        with span("executor.run"):
+            return self._run_many(programs, materialize, config)
+
+    def _run_many(self, programs, materialize, config):
+        """:meth:`run_many` inside its ``executor.run`` span."""
         ops = programs[0].ops
         for prog in programs[1:]:
             if prog.ops != ops:
@@ -1320,6 +1368,8 @@ class DataplaneExecutor:
         self._phase_us = {"host_prep": 0.0, "compile": 0.0, "launch": 0.0, "sync": 0.0}
         self._round_us = {}
         self._device_rows = {}
+        self._h2d_bytes = self._d2h_bytes = self._host_syncs = 0
+        self._lowering_us = 0.0
         self._deadline = config.deadline if config is not None else None
         self._fault_plan_run = (
             config.fault_plan if config is not None and config.fault_plan is not None
@@ -1344,7 +1394,12 @@ class DataplaneExecutor:
                     ) from None
                 live = [state for state in states if not state.empty]
                 if live:
-                    lower(programs[0], live, op)
+                    in_rounds = sum(self._round_us.values())
+                    with span("executor.op", round=op.round) as sp:
+                        lower(programs[0], live, op)
+                    self._lowering_us += sp.us - (
+                        sum(self._round_us.values()) - in_rounds
+                    )
         except BaseException:
             # cache quarantine: a failed attempt may have written (or left
             # half-doubled) learned caps anywhere it ran — drop every entry
@@ -1374,49 +1429,60 @@ class DataplaneExecutor:
             phase_us=dict(self._phase_us),
             round_us=dict(self._round_us),
             device_rows={k: v.tolist() for k, v in self._device_rows.items()},
+            h2d_bytes=self._h2d_bytes,
+            d2h_bytes=self._d2h_bytes,
+            host_syncs=self._host_syncs,
         )
         results: List[DataplaneJoinResult] = []
-        for qi, program in enumerate(programs):
-            counts: Dict[Tuple[Attr, ...], int] = defaultdict(int)
-            chunks: List[np.ndarray] = []
-            for mid, row in program.emit:
-                chunks.append(row)
-            for hkey, c in program.emit_counts.items():
-                counts[hkey] += c
-            for state in states:
-                if state.qi != qi or state.skip_count:
-                    continue
-                counts[state.stage.hkey] += state.n_out
-                if state.rows is not None and state.rows.shape[0]:
-                    chunks.append(state.rows)
+        with span("executor.assemble") as sp:
+            for qi, program in enumerate(programs):
+                counts: Dict[Tuple[Attr, ...], int] = defaultdict(int)
+                chunks: List[np.ndarray] = []
+                for mid, row in program.emit:
+                    chunks.append(row)
+                for hkey, c in program.emit_counts.items():
+                    counts[hkey] += c
+                for state in states:
+                    if state.qi != qi or state.skip_count:
+                        continue
+                    counts[state.stage.hkey] += state.n_out
+                    if state.rows is not None and state.rows.shape[0]:
+                        chunks.append(state.rows)
 
-            rows_out = None
-            if materialize:
-                rows_out = (
-                    np.concatenate(chunks, axis=0)
-                    if chunks
-                    else np.zeros((0, len(program.out_cols)), dtype=np.int64)
-                )
-            results.append(DataplaneJoinResult(
-                p=self.p,
-                count=sum(counts.values()),
-                rows=rows_out,
-                per_h_counts=dict(counts),
-                retries=self._qi_retries.get(qi, 0),
-                retry_log=list(self._qi_retry_log.get(qi, [])),
-                dispatches=batch.dispatches,
-                jit_cache_hits=batch.jit_cache_hits,
-                jit_cache_misses=batch.jit_cache_misses,
-                caps_hits=batch.caps_hits,
-                caps_misses=batch.caps_misses,
-                caps_evictions=batch.caps_evictions,
-                bucket_stage_counts={
-                    k: list(v) for k, v in batch.bucket_stage_counts.items()
-                },
-                phase_us=dict(batch.phase_us),
-                round_us=dict(batch.round_us),
-                device_rows={k: list(v) for k, v in batch.device_rows.items()},
-            ))
+                rows_out = None
+                if materialize:
+                    rows_out = (
+                        np.concatenate(chunks, axis=0)
+                        if chunks
+                        else np.zeros((0, len(program.out_cols)), dtype=np.int64)
+                    )
+                results.append(DataplaneJoinResult(
+                    p=self.p,
+                    count=sum(counts.values()),
+                    rows=rows_out,
+                    per_h_counts=dict(counts),
+                    retries=self._qi_retries.get(qi, 0),
+                    retry_log=list(self._qi_retry_log.get(qi, [])),
+                    dispatches=batch.dispatches,
+                    jit_cache_hits=batch.jit_cache_hits,
+                    jit_cache_misses=batch.jit_cache_misses,
+                    caps_hits=batch.caps_hits,
+                    caps_misses=batch.caps_misses,
+                    caps_evictions=batch.caps_evictions,
+                    bucket_stage_counts={
+                        k: list(v) for k, v in batch.bucket_stage_counts.items()
+                    },
+                    phase_us=dict(batch.phase_us),
+                    round_us=dict(batch.round_us),
+                    device_rows={k: list(v) for k, v in batch.device_rows.items()},
+                    h2d_bytes=batch.h2d_bytes,
+                    d2h_bytes=batch.d2h_bytes,
+                    host_syncs=batch.host_syncs,
+                ))
+            sp.set(rows=sum(r.count for r in results))
+        batch.lowering_us = self._lowering_us + sp.us
+        for r in results:
+            r.lowering_us = batch.lowering_us
         return results, batch
 
     # -- robustness hooks ------------------------------------------------------
@@ -1549,8 +1615,15 @@ class DataplaneExecutor:
         if not items:
             return items
         self._check_deadline(round_name)
+        with span("executor.round", round=round_name) as sp:
+            self._schedule_round(round_name, items, dispatch)
+        self._round_us[round_name] = self._round_us.get(round_name, 0.0) + sp.us
+        return items
+
+    def _schedule_round(self, round_name: str, items: List[_WorkItem], dispatch):
+        """The body of :meth:`_run_buckets`: each pass builds, compiles,
+        launches and reads back every pending bucket under its phase span."""
         fp = self._fault_plan_run
-        t_round = time.perf_counter()
         phase = self._phase_us
 
         # Learned capacities: start each item at the caps its (round, group,
@@ -1613,32 +1686,30 @@ class DataplaneExecutor:
             to_compile: Dict[Tuple, Tuple] = {}
             cache = self.compiled_cache
             executables: Dict[Tuple, object] = {}
-            t0 = time.perf_counter()
-            for bucket in bucket_list:
-                sig = (
-                    self.mesh,
-                    self.axis_name,
-                    round_name,
-                    bucket[0].key,
-                    tuple(sorted(bucket[0].caps.items())),
-                    self._pow2_stages(len(bucket)),
-                )
-                fn, args, post = dispatch(bucket)
-                if sig not in executables and sig not in to_compile:
-                    exe = cache.get(sig)
-                    if exe is not None:
-                        executables[sig] = exe
-                if sig in executables or sig in to_compile:
-                    self._jit_hits += 1
-                else:
-                    to_compile[sig] = (fn, args)
-                    self._jit_misses += 1
-                self._dispatches += 1
-                self._bucket_log.setdefault(round_name, []).append(len(bucket))
-                prepared.append((bucket, sig, args, post))
-            phase["host_prep"] = phase.get("host_prep", 0.0) + (
-                time.perf_counter() - t0
-            ) * 1e6
+            with span("executor.host_prep", round=round_name) as sp:
+                for bucket in bucket_list:
+                    sig = (
+                        self.mesh,
+                        self.axis_name,
+                        round_name,
+                        bucket[0].key,
+                        tuple(sorted(bucket[0].caps.items())),
+                        self._pow2_stages(len(bucket)),
+                    )
+                    fn, args, post = dispatch(bucket)
+                    if sig not in executables and sig not in to_compile:
+                        exe = cache.get(sig)
+                        if exe is not None:
+                            executables[sig] = exe
+                    if sig in executables or sig in to_compile:
+                        self._jit_hits += 1
+                    else:
+                        to_compile[sig] = (fn, args)
+                        self._jit_misses += 1
+                    self._dispatches += 1
+                    self._bucket_log.setdefault(round_name, []).append(len(bucket))
+                    prepared.append((bucket, sig, args, post))
+            phase["host_prep"] = phase.get("host_prep", 0.0) + sp.us
 
             # AOT-compile the round's unseen signatures concurrently: XLA
             # compilation releases the GIL, so distinct executables compile
@@ -1647,7 +1718,6 @@ class DataplaneExecutor:
             # different collective programs interleave their all_to_all
             # rendezvous across the device threads and deadlock.
             if to_compile:
-                t0 = time.perf_counter()
 
                 def compile_one(item):
                     sig, (fn, args) = item
@@ -1655,67 +1725,73 @@ class DataplaneExecutor:
                         fp.at_compile(round_name)
                     return sig, fn.lower(*args).compile()
 
-                todo = list(to_compile.items())
-                if len(todo) > 1:
-                    from concurrent.futures import ThreadPoolExecutor
+                with span("executor.compile", round=round_name) as sp:
+                    todo = list(to_compile.items())
+                    if len(todo) > 1:
+                        from concurrent.futures import ThreadPoolExecutor
 
-                    workers = min(len(todo), max(2, os.cpu_count() or 2))
-                    with ThreadPoolExecutor(max_workers=workers) as pool:
-                        for sig, comp in pool.map(compile_one, todo):
-                            cache.put(sig, comp)
-                            executables[sig] = comp
-                else:
-                    sig, comp = compile_one(todo[0])
-                    cache.put(sig, comp)
-                    executables[sig] = comp
-                phase["compile"] = phase.get("compile", 0.0) + (
-                    time.perf_counter() - t0
-                ) * 1e6
+                        workers = min(len(todo), max(2, os.cpu_count() or 2))
+                        with ThreadPoolExecutor(max_workers=workers) as pool:
+                            for sig, comp in pool.map(compile_one, todo):
+                                cache.put(sig, comp)
+                                executables[sig] = comp
+                    else:
+                        sig, comp = compile_one(todo[0])
+                        cache.put(sig, comp)
+                        executables[sig] = comp
+                phase["compile"] = phase.get("compile", 0.0) + sp.us
 
-            t0 = time.perf_counter()
             launched = []
-            for bucket, sig, args, post in prepared:
-                self._check_deadline(round_name)
-                if fp is not None:
-                    fp.at_dispatch(round_name)
-                launched.append((bucket, *post(executables[sig](*args))))
-            phase["launch"] = phase.get("launch", 0.0) + (
-                time.perf_counter() - t0
-            ) * 1e6
+            h2d = d2h = reads = 0
+            with span("executor.launch", round=round_name) as sp:
+                for bucket, sig, args, post in prepared:
+                    self._check_deadline(round_name)
+                    if fp is not None:
+                        fp.at_dispatch(round_name)
+                    h2d += _host_nbytes(args)
+                    outs = executables[sig](*args)
+                    finalize, ovf = post(outs)
+                    nbytes, n = _pulled(outs, ovf)
+                    d2h, reads = d2h + nbytes, reads + n
+                    launched.append((bucket, finalize, ovf))
+                sp.set(h2d_bytes=h2d)
+            phase["launch"] = phase.get("launch", 0.0) + sp.us
+            self._h2d_bytes += h2d
 
             # one deferred readback per (op, bucket): the scheduler's only
             # host sync — every bucket's collectives are already in flight.
-            t0 = time.perf_counter()
             tripped: Dict[int, set] = {}
-            for bucket, finalize, ovf in launched:
-                ovf_np = np.asarray(ovf)
-                results = finalize()
-                for i, it in enumerate(bucket):
-                    tot = ovf_np[i].reshape(-1, 2).sum(axis=0)
-                    kinds = set()
-                    if int(tot[0]):
-                        kinds.add("slot")
-                    if int(tot[1]):
-                        kinds.add("out")
-                    if fp is not None:
-                        # injected overflow: forced channels read exactly like
-                        # real trips (doubling, re-salting, retry accounting),
-                        # but the item's learned-caps slot is tainted so the
-                        # inflated caps are never written back.
-                        forced = {
-                            ch for ch in fp.overflow(round_name) if ch in it.caps
-                        }
-                        if forced:
-                            kinds |= forced
-                            if self._tainted_caps is not None:
-                                self._tainted_caps.add(
-                                    self._caps_key(round_name, it)
-                                )
-                    tripped[id(it)] = kinds
-                    it.result = results[i]
-            phase["sync"] = phase.get("sync", 0.0) + (
-                time.perf_counter() - t0
-            ) * 1e6
+            with span("executor.sync", round=round_name, d2h_bytes=d2h) as sp:
+                for bucket, finalize, ovf in launched:
+                    ovf_np = np.asarray(ovf)
+                    results = finalize()
+                    for i, it in enumerate(bucket):
+                        tot = ovf_np[i].reshape(-1, 2).sum(axis=0)
+                        kinds = set()
+                        if int(tot[0]):
+                            kinds.add("slot")
+                        if int(tot[1]):
+                            kinds.add("out")
+                        if fp is not None:
+                            # injected overflow: forced channels read exactly
+                            # like real trips (doubling, re-salting, retry
+                            # accounting), but the item's learned-caps slot is
+                            # tainted so the inflated caps are never written
+                            # back.
+                            forced = {
+                                ch for ch in fp.overflow(round_name) if ch in it.caps
+                            }
+                            if forced:
+                                kinds |= forced
+                                if self._tainted_caps is not None:
+                                    self._tainted_caps.add(
+                                        self._caps_key(round_name, it)
+                                    )
+                        tripped[id(it)] = kinds
+                        it.result = results[i]
+            phase["sync"] = phase.get("sync", 0.0) + sp.us
+            self._d2h_bytes += d2h
+            self._host_syncs += reads
 
             group_kinds: Dict[Tuple, set] = {}
             for it in pending:
@@ -1805,10 +1881,6 @@ class DataplaneExecutor:
             self._learned_caps.popitem(last=False)
             self._caps_evictions += 1
             self.caps_evictions += 1
-        self._round_us[round_name] = self._round_us.get(round_name, 0.0) + (
-            time.perf_counter() - t_round
-        ) * 1e6
-        return items
 
     def _apply_exact_caps(self, round_name, items, count_dispatch, caps_from_count,
                           floor):

@@ -72,6 +72,7 @@ from ..core.hypergraph import rho
 from ..core.planner import heavy_parameter
 from ..core.query import Attr, JoinQuery
 from ..core.taxonomy import HeavyStats, compute_stats
+from ..obs import new_request_id, span
 from ..train.fault import Heartbeat, StragglerMonitor
 from .executors import (
     DataplaneExecutor,
@@ -219,7 +220,12 @@ class SessionResult:
     simulator, :class:`DataplaneJoinResult` on the dataplane); the convenience
     properties forward the common fields.  ``plan_cache_hit`` says whether the
     plan LRU served the compiled program; the ``*_us`` fields break the
-    submit's wall-clock into statistics / compile / execute phases.
+    submit's wall-clock into statistics / compile / execute phases;
+    ``stats_us``, ``compile_us`` and ``verify_us`` are the durations of the
+    ``planner.stats``, ``planner.compile`` and ``planner.verify`` spans
+    (:mod:`repro.obs`).
+    ``request_id`` is the id the request got at admission, which its
+    ``service.submit`` or ``service.batch`` span carries in a trace.
 
     Coalescing provenance: ``coalesced`` is True when the request ran inside
     a multi-query scheduler pass (its ``execute_us`` is then the *shared*
@@ -252,6 +258,7 @@ class SessionResult:
     #: ``total_us``).
     verified: bool = False
     verify_us: float = 0.0
+    request_id: int = 0
 
     @property
     def count(self) -> int:
@@ -304,6 +311,7 @@ class _Request:
     future: Optional[Future] = None       # async submits resolve through this
     t_enqueue: Optional[float] = None     # perf_counter at queue admission
     deadline: Optional[float] = None      # absolute monotonic budget (or None)
+    rid: int = field(default_factory=new_request_id)   # id given at admission
     # filled by _prepare:
     executor: object = None
     program: Optional[RoundProgram] = None
@@ -470,7 +478,8 @@ class JoinSession:
             h_subsets=h_subsets, fuse_semijoin=fuse_semijoin, batch=_batch,
             deadline=self._abs_deadline(deadline_s),
         )
-        out = self._execute_batch([req])[0]
+        with span("service.submit", requests=(req.rid,)):
+            out = self._execute_batch([req])[0]
         if isinstance(out, BaseException):
             # re-raise with the stored traceback intact (the original frames
             # would otherwise be replaced by this raise site)
@@ -564,7 +573,8 @@ class JoinSession:
             )
             for q in queries
         ]
-        outs = self._execute_batch(reqs)
+        with span("service.submit", requests=[r.rid for r in reqs]):
+            outs = self._execute_batch(reqs)
         for out in outs:
             if isinstance(out, BaseException):
                 raise out.with_traceback(out.__traceback__)
@@ -743,12 +753,19 @@ class JoinSession:
     def _process(self, batch: List[_Request]) -> None:
         """Execute one drain batch and resolve its futures (never raises —
         a drainer must survive any single request's failure)."""
-        try:
-            outs = self._execute_batch(batch)
-        except BaseException as e:  # defensive: _execute_batch reports per-request
-            outs = [e] * len(batch)
-        for req, out in zip(batch, outs):
-            self._resolve(req, out)
+        now = time.perf_counter()
+        queued = " ".join(
+            str(round((now - r.t_enqueue) * 1e6)) if r.t_enqueue is not None else "0"
+            for r in batch
+        )
+        with span("service.batch", requests=[r.rid for r in batch], queued_us=queued):
+            try:
+                outs = self._execute_batch(batch)
+            except BaseException as e:  # defensive: _execute_batch reports per-request
+                outs = [e] * len(batch)
+            with span("service.resolve"):
+                for req, out in zip(batch, outs):
+                    self._resolve(req, out)
 
     # -- the shared execution path --------------------------------------------
 
@@ -773,20 +790,20 @@ class JoinSession:
                 else:
                     lam = heavy_parameter(self.p, float(rho(req.query)))
 
-            t0 = time.perf_counter()
-            if self.backend == "simulator":
-                sim = MPCSimulator(self.p, seed=self.seed)
-                executor: object = SimulatorExecutor(sim, seed=self.seed)
-                executor.place_inputs(req.query, scatter_cache=share.get("scatter"))
-                if stats is None:
-                    stats = distributed_stats(sim, req.query, lam)
-            else:
-                executor = self.executor
-                if stats is None:
-                    stats = compute_stats(
-                        req.query, lam, unique_memo=share.get("unique")
-                    )
-            req.stats_us = (time.perf_counter() - t0) * 1e6
+            with span("planner.stats", request=req.rid) as sp:
+                if self.backend == "simulator":
+                    sim = MPCSimulator(self.p, seed=self.seed)
+                    executor: object = SimulatorExecutor(sim, seed=self.seed)
+                    executor.place_inputs(req.query, scatter_cache=share.get("scatter"))
+                    if stats is None:
+                        stats = distributed_stats(sim, req.query, lam)
+                else:
+                    executor = self.executor
+                    if stats is None:
+                        stats = compute_stats(
+                            req.query, lam, unique_memo=share.get("unique")
+                        )
+            req.stats_us = sp.us
 
             key = plan_cache_key(req.query, stats, self.p, req.h_subsets, fuse)
             cached = self._plans.get(key)
@@ -797,24 +814,24 @@ class JoinSession:
                 if self.verify:
                     # warm path: the cached plan was fully verified when it
                     # was compiled; only the fresh bindings need re-checking.
-                    t0 = time.perf_counter()
-                    verify_bindings(req.program)
-                    req.verify_us = (time.perf_counter() - t0) * 1e6
+                    with span("planner.verify", request=req.rid) as sp:
+                        verify_bindings(req.program)
+                    req.verify_us = sp.us
             else:
-                t0 = time.perf_counter()
-                req.program = compile_plan(
-                    req.query, stats, self.p,
-                    h_subsets=req.h_subsets, fuse_semijoin=fuse,
-                    verify=False,  # timed separately below
-                )
-                req.compile_us = (time.perf_counter() - t0) * 1e6
-                if self.verify:
-                    t0 = time.perf_counter()
-                    verify_program(
-                        req.program,
-                        caps=getattr(executor, "_learned_caps", None),
+                with span("planner.compile", request=req.rid) as sp:
+                    req.program = compile_plan(
+                        req.query, stats, self.p,
+                        h_subsets=req.h_subsets, fuse_semijoin=fuse,
+                        verify=False,  # timed separately below
                     )
-                    req.verify_us = (time.perf_counter() - t0) * 1e6
+                req.compile_us = sp.us
+                if self.verify:
+                    with span("planner.verify", request=req.rid) as sp:
+                        verify_program(
+                            req.program,
+                            caps=getattr(executor, "_learned_caps", None),
+                        )
+                    req.verify_us = sp.us
                     req.verified = True
                 # cache plan metadata only: the concrete relations are rebound
                 # on every hit, so pinning the first submitter's tuple data in
@@ -949,12 +966,13 @@ class JoinSession:
                     execute_us = (time.perf_counter() - t0) * 1e6
                     self._absorb(bstats)
                     coalesced = len(members) > 1
-                    for req, ri in zip(members, assign):
-                        outs[id(req)] = self._wrap(
-                            req, results[ri], execute_us, len(reqs),
-                            coalesced=coalesced,
-                            deduplicated=(req is not reps[ri]),
-                        )
+                    with span("service.resolve"):
+                        for req, ri in zip(members, assign):
+                            outs[id(req)] = self._wrap(
+                                req, results[ri], execute_us, len(reqs),
+                                coalesced=coalesced,
+                                deduplicated=(req is not reps[ri]),
+                            )
 
             if len(reqs) > 1:
                 self.stats.coalesced_batches += 1
@@ -1114,6 +1132,7 @@ class JoinSession:
             deduplicated=deduplicated,
             verified=req.verified,
             verify_us=req.verify_us,
+            request_id=req.rid,
         )
 
     # -- batch entry ----------------------------------------------------------
