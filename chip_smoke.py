@@ -11,9 +11,10 @@ that does not use the engine:
      equal a scipy.sparse count over the degree-oriented adjacency, and the
      warm submit must compile nothing and retry nothing. 2^20 edges, the
      scale of SNAP's com-DBLP and com-Amazon, is halved once: on one chip
-     every LocalJoin level probes whole device blocks pair by pair (O(N·M)),
-     and compiling the 2^20 executables for a v5e takes ~450 s on an 8-core
-     host (mostly XLA's sorts of multi-million-row blocks);
+     every LocalJoin level probes whole device blocks (when the size was
+     chosen the probe swept all tile pairs, O(N·M)), and compiling the 2^20
+     executables for a v5e takes ~450 s on an 8-core host (mostly XLA's
+     sorts of multi-million-row blocks);
   b. binary queries with planted hubs (``hub_triangle_query``, and
      ``hub_star_query`` whose hub stage is a pure cartesian-product grid) and
      an acyclic 3-ary star (``general_query("star3")``); rows must be

@@ -1,5 +1,5 @@
 # Pallas TPU kernels for the compute hot-spots of the join data plane + the models:
-#   merge_join.py      — sorted-key join probe (tiled compare-reduce over VMEM blocks)
+#   merge_join.py      — sorted-key join probe and pair expansion (merge-path tile schedule)
 #   hash_partition.py  — multiplicative hash + per-tile radix histogram
 #   ssd.py             — Mamba-2/SSD intra-chunk masked matmul + state update
 #   flash_attention.py — online-softmax attention (the dominant memory term's fix)

@@ -113,10 +113,13 @@ def merge_join_counts(a_keys: jax.Array, b_keys: jax.Array, use_pallas: bool = T
     if not use_pallas:
         return _ref.merge_join_counts_ref(a_keys, b_keys)
     big = jnp.iinfo(jnp.int32).max
-    a_p = _pad_to(a_keys, _mj.BLOCK_A, big)
-    b_p = _pad_to(b_keys, _mj.BLOCK_B, big)
+    a_p = _pad_to(a_keys, _mj.BLOCK, big)
+    b_p = _pad_to(b_keys, _mj.BLOCK, big)
+    # the schedule is built outside the per-problem kernel call, so that a
+    # vmapped probe traces it once
+    sched, _ = _mj.counts_schedule(a_p[None], b_p[None])
     lower, upper = _one_problem(
-        functools.partial(_mj.merge_join_counts_pallas, interpret=interpret), a_p, b_p
+        functools.partial(_mj.merge_join_counts_pallas, interpret=interpret), sched[0], a_p, b_p
     )
     # padded B sentinels never compare < or <= real keys except vs the padded A
     # sentinels; trim A and clamp to the true M.
@@ -142,15 +145,17 @@ def merge_join_pairs(lower: jax.Array, starts: jax.Array, cap_out: int,
     if not use_pallas:
         return _ref.merge_join_pairs_ref(lower, starts, cap_out)
     big = jnp.iinfo(jnp.int32).max
-    dl = jnp.diff(lower.astype(jnp.int32), prepend=jnp.int32(0))
-    ds = jnp.diff(starts.astype(jnp.int32), prepend=jnp.int32(0))
-    starts_p = _pad_to(starts.astype(jnp.int32), _mj.BLOCK_A, big)
-    dl_p = _pad_to(dl, _mj.BLOCK_A, 0)
-    ds_p = _pad_to(ds, _mj.BLOCK_A, 0)
-    cap_p = -(-cap_out // _mj.BLOCK_T) * _mj.BLOCK_T
-    a_idx, b_idx, _ = _one_problem(
+    starts_p = _pad_to(starts.astype(jnp.int32), _mj.BLOCK, big)
+    # lower - starts, differenced within each block (the block's first key keeps
+    # its own value): the kernel's telescoping sums end at the last hit
+    v = _pad_to(lower.astype(jnp.int32) - starts.astype(jnp.int32), _mj.BLOCK, 0)
+    v = v.reshape(-1, _mj.BLOCK)
+    dd = (v - jnp.pad(v[:, :-1], ((0, 0), (1, 0)))).reshape(-1)
+    cap_p = -(-cap_out // _mj.BLOCK) * _mj.BLOCK
+    sched, _ = _mj.pairs_schedule(starts_p[None], cap_p)
+    a_idx, b_idx = _one_problem(
         functools.partial(_mj.merge_join_pairs_pallas, cap_out=cap_p, interpret=interpret),
-        starts_p, dl_p, ds_p,
+        sched[0], starts_p, dd,
     )
     return jnp.clip(a_idx[:cap_out], 0, n - 1), b_idx[:cap_out]
 

@@ -161,6 +161,155 @@ def test_merge_join_total_pairs_vs_join():
 
 
 # ---------------------------------------------------------------------------
+# merge-path schedule: skewed, sentinel and disjoint inputs
+# ---------------------------------------------------------------------------
+
+BIG = np.iinfo(np.int32).max
+
+
+def _sorted(*parts):
+    return np.sort(np.concatenate(parts).astype(np.int32))
+
+
+def _merge_path_cases():
+    """(name, A (P, n), B (P, m)): sorted rows, one problem unless batched; each
+    side 17 or more blocks, so that the kernels run the merge-path schedule
+    and not the dense sweep of small probes."""
+    rng = np.random.default_rng(17)
+    r = lambda lo, hi, k: rng.integers(lo, hi, k)
+    one = lambda a, b: (np.asarray(a)[None], np.asarray(b)[None])
+    zipf = lambda k, dom: np.minimum(rng.zipf(1.3, k), dom)
+    batch_a = np.stack([_sorted(r(0, 9000, 17000)), _sorted(zipf(17000, 60)),
+                        _sorted(r(0, 9000, 11000), np.full(6000, BIG))])
+    batch_b = np.stack([_sorted(r(0, 9000, 18500)), _sorted(zipf(18500, 60)),
+                        _sorted(r(4000, 5000, 14000), np.full(4500, BIG))])
+    return [
+        ("all-keys-equal", *one(np.full(17 * 1024, 7), np.full(19 * 1024, 7))),
+        # key 50 fills 5 whole A tiles and 7 whole B blocks, with keys either side
+        ("heavy-key", *one(_sorted(r(0, 50, 6 * 1024), np.full(5 * 1024, 50), r(51, 99, 6500)),
+                           _sorted(r(0, 50, 6 * 1024), np.full(7 * 1024, 50), r(51, 99, 6000)))),
+        ("a-all-sentinel", *one(np.full(17 * 1024, BIG), _sorted(r(0, 500, 19000)))),
+        ("b-all-sentinel", *one(_sorted(r(0, 500, 17000)), np.full(18 * 1024, BIG))),
+        ("a-below-b", *one(_sorted(r(0, 1000, 17000)), _sorted(r(2000, 3000, 19000)))),
+        ("a-above-b", *one(_sorted(r(2000, 3000, 17000)), _sorted(r(0, 1000, 19000)))),
+        ("interleaved", *one(2 * np.arange(17 * 1024), 2 * np.arange(18 * 1024) + 1)),
+        ("ragged", *one(_sorted(r(0, 3000, 17 * 1024 + 1)),
+                        _sorted(r(0, 3000, 19 * 1024 - 6), np.full(5, BIG)))),
+        ("batch-of-three", batch_a, batch_b),
+    ]
+
+
+MERGE_PATH_CASES = _merge_path_cases()
+
+
+def _padded(x, fill):
+    return np.pad(x, ((0, 0), (0, -x.shape[1] % 1024)), constant_values=fill)
+
+
+def _for_each_problem(fn, *xs):
+    if xs[0].shape[0] == 1:
+        return [np.asarray(o)[None] for o in fn(*(jnp.asarray(x[0]) for x in xs))]
+    return [np.asarray(o) for o in jax.vmap(fn)(*(jnp.asarray(x) for x in xs))]
+
+
+@pytest.mark.parametrize("name,a,b", MERGE_PATH_CASES, ids=[c[0] for c in MERGE_PATH_CASES])
+def test_merge_path_kernels_match_ref(name, a, b):
+    """Both kernels are bit-identical to the jnp reference on skewed, sentinel,
+    disjoint and batched inputs, and no schedule has more steps than its static
+    bound, which is below the dense sweep's."""
+    from repro.kernels import merge_join as mj
+
+    counts = lambda up: _for_each_problem(
+        lambda x, y: merge_join_counts(x, y, use_pallas=up), a, b)
+    (lo, up), (lo_r, up_r) = counts(True), counts(False)
+    np.testing.assert_array_equal(lo, lo_r)
+    np.testing.assert_array_equal(up, up_r)
+
+    # the pair list of the probe, built as local_sorted_join builds it
+    n_match = np.where(a < BIG, up - lo, 0)
+    starts = (np.cumsum(n_match, axis=1) - n_match).astype(np.int32)
+    cap_out = 20 * 1024 - 500
+    pairs = lambda use: _for_each_problem(
+        lambda lw, st: merge_join_pairs(lw, st, cap_out, use_pallas=use), lo, starts)
+    for k, r in zip(pairs(True), pairs(False)):
+        np.testing.assert_array_equal(k, r)
+
+    a_p, b_p = _padded(a, BIG), _padded(b, BIG)
+    na, nb, nt = a_p.shape[1] // 1024, b_p.shape[1] // 1024, -(-cap_out // 1024)
+    _, active = mj.counts_schedule(jnp.asarray(a_p), jnp.asarray(b_p))
+    assert mj.counts_steps(na, nb) < na * nb
+    assert np.all(np.asarray(active) <= mj.counts_steps(na, nb)), (active, na, nb)
+    _, active = mj.pairs_schedule(jnp.asarray(_padded(starts, BIG)), nt * 1024)
+    assert mj.pairs_steps(nt, na) < nt * na
+    assert np.all(np.asarray(active) <= mj.pairs_steps(nt, na)), (active, nt, na)
+
+
+def test_problems_beyond_smem_split_across_calls(monkeypatch):
+    """A batch whose schedules outgrow SMEM_STEPS runs in several kernel calls,
+    with the same answers."""
+    from repro.kernels import merge_join as mj
+
+    (_, a, b), = [c for c in MERGE_PATH_CASES if c[0] == "batch-of-three"]
+    steps = mj.counts_steps(-(-a.shape[1] // 1024), -(-b.shape[1] // 1024))
+    monkeypatch.setattr(mj, "SMEM_STEPS", 2 * steps)          # two problems a call
+    counts = lambda up: jax.vmap(lambda x, y: merge_join_counts(x, y, use_pallas=up))(
+        jnp.asarray(a), jnp.asarray(b))
+    for k, r in zip(counts(True), counts(False)):
+        np.testing.assert_array_equal(np.asarray(k), np.asarray(r))
+
+
+def test_one_executable_per_shape():
+    """Keys of one shape and different distributions run one executable of each
+    kernel: no key value reaches a grid extent or a static argument."""
+    compiles = []
+
+    def on_compile(event, duration, **kwargs):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(kwargs.get("fun_name"))
+
+    rng = np.random.default_rng(3)
+    inputs = [(np.sort(rng.integers(0, 10**6, 17003)), np.sort(rng.integers(0, 10**6, 19007))),
+              (np.full(17003, 4), np.sort(rng.integers(0, 9, 19007))),
+              (np.sort(rng.integers(0, 50, 17003)), np.full(19007, BIG))]
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        for a, b in inputs:
+            lo, up = merge_join_counts(jnp.asarray(a, jnp.int32), jnp.asarray(b, jnp.int32))
+            n_match = np.asarray(up) - np.asarray(lo)
+            starts = jnp.asarray(np.cumsum(n_match) - n_match, jnp.int32)
+            jax.block_until_ready(merge_join_pairs(lo, starts, 20001))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_compile)
+    assert compiles.count("jit(merge_join_counts)") == 1, compiles
+    assert compiles.count("jit(merge_join_pairs)") == 1, compiles
+
+
+def test_probe_schedule_span_at_trace_time(monkeypatch):
+    """Tracing a probe of a new shape opens one kernel.probe_schedule span with
+    the static schedule length; calling it again traces nothing."""
+    from repro import obs
+
+    opened = []
+    inner = obs.span
+
+    def span(name, **args):
+        opened.append((name, args))
+        return inner(name, **args)
+
+    monkeypatch.setattr(obs, "span", span)
+    a = jnp.zeros((16 * 1024 + 5,), jnp.int32)
+    b = jnp.zeros((17 * 1024 + 7,), jnp.int32)
+    for _ in range(2):
+        merge_join_counts(a, b)
+    assert opened == [("kernel.probe_schedule",
+                       dict(problems=1, n=17 * 1024, m=18 * 1024, steps=68, dense_steps=306))]
+    opened.clear()
+    merge_join_pairs(a, a, 16 * 1024)
+    assert opened == [("kernel.probe_schedule",
+                       dict(problems=1, n=17 * 1024, cap_out=16 * 1024, steps=32, dense_steps=272))]
+
+
+# ---------------------------------------------------------------------------
 # hash_partition
 # ---------------------------------------------------------------------------
 

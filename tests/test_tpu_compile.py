@@ -85,6 +85,18 @@ KERNEL_CASES = [
      [(4, 3000), (4, 5000)]),
     ("hash_partition_pack-vmapped", jax.vmap(lambda k, c: ops.hash_partition_pack(k, c, 16)),
      [(4, 5000), (4,)]),
+    # the benchmark cells' largest probes, one problem each: tri-dblp.enum's
+    # LocalJoin (524,288 keys against 196,608, pairs into 524,288 slots) and
+    # ssb-q4.year-count's padded fact table against a dimension
+    ("merge_join_counts-tri", lambda a, b: ops.merge_join_counts(a, b),
+     [(524288,), (196608,)]),
+    ("merge_join_pairs-tri", lambda lo, st: ops.merge_join_pairs(lo, st, 524288),
+     [(196608,), (196608,)]),
+    ("merge_join_counts-year", lambda a, b: ops.merge_join_counts(a, b),
+     [(4194304,), (32768,)]),
+    # 64 schedules of 6,142 steps outgrow the 1 MiB of SMEM: several calls
+    ("merge_join_counts-vmapped-smem", jax.vmap(lambda a, b: ops.merge_join_counts(a, b)),
+     [(64, 1 << 21), (64, 1 << 20)]),
 ]
 
 
@@ -92,6 +104,36 @@ KERNEL_CASES = [
 def test_join_kernel_compiles_for_v5e(on_chip, one_chip, name, fn, shapes):
     args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip) for s in shapes]
     assert "tpu_custom_call" in _hlo(fn, *args), name
+
+
+def test_probe_roofline_reads_the_key_lengths(on_chip, one_chip):
+    """kernel.probe_roofline takes the first two 1-D s32 operands of the counts
+    kernel's custom call as A's and B's key lengths; the schedule goes first,
+    and 2-D, so it is not among them."""
+    import re
+
+    shape = re.compile(r"s32\[(\d+)\]")      # the reader's pattern
+    args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip) for s in [(524288,), (196608,)]]
+    hlo = _hlo(lambda a, b: ops.merge_join_counts(a, b), *args)
+    (call,) = [l for l in hlo.splitlines() if "merge_join_counts" in l and "custom-call(" in l]
+    operands = re.search(r"operand_layout_constraints=\{((?:[^{}]|\{[^{}]*\})*)\}", call).group(1)
+    assert [int(x) for x in shape.findall(operands)[:2]] == [524288, 196608], operands
+
+
+@pytest.mark.parametrize("kernel", ["counts", "pairs"])
+def test_probe_lowering_ignores_key_values(on_chip, one_chip, kernel):
+    """The TPU lowering of a probe is the same for any keys of one shape."""
+    rng = np.random.default_rng(0)
+    inputs = [(np.sort(rng.integers(0, 100, 17000)), np.sort(rng.integers(0, 100, 19000))),
+              (np.full(17000, 3), np.full(19000, np.iinfo(np.int32).max))]
+    if kernel == "counts":
+        fn = jax.jit(lambda a, b: ops.merge_join_counts(a, b), in_shardings=(one_chip, one_chip))
+    else:
+        fn = jax.jit(lambda lo, st: ops.merge_join_pairs(lo, st, 20000),
+                     in_shardings=(one_chip, one_chip))
+        inputs = [(a, np.cumsum(a) - a) for a, _ in inputs]
+    texts = [fn.lower(*(np.asarray(x, np.int32) for x in xs)).as_text() for xs in inputs]
+    assert "tpu_custom_call" in texts[0] and texts[0] == texts[1]
 
 
 def _abstract(args, mesh):
