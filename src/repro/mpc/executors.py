@@ -25,6 +25,7 @@ from __future__ import annotations
 import hashlib
 import math
 import os
+import re
 import time
 import warnings
 from collections import defaultdict
@@ -821,6 +822,10 @@ class DataplaneJoinResult:
     h2d_bytes: int = 0
     d2h_bytes: int = 0
     host_syncs: int = 0
+    #: bytes the launches' ``all_to_all`` send buffers carried, summed over the
+    #: devices (stages × p × slot capacity × row width × 4 per device and
+    #: launch, plus the per-destination counts), from the lowered shapes.
+    a2a_bytes: int = 0
     #: host time (µs) of the lowering rules outside their op rounds (blocking,
     #: key packing, unblocking) plus result assembly: ``executor.op`` spans
     #: less their ``executor.round`` children, plus ``executor.assemble``.
@@ -847,13 +852,16 @@ class ExecutableCache:
     accumulating XLA binaries forever.
 
     ``hits`` / ``misses`` / ``evictions`` meter the cache's whole lifetime
-    (per-run counts live on :class:`DataplaneJoinResult`)."""
+    (per-run counts live on :class:`DataplaneJoinResult`).  Each entry keeps
+    the ``all_to_all`` bytes one launch of its executable sends
+    (:func:`_all_to_all_bytes`), counted once when it is compiled."""
 
     def __init__(self, capacity: int = 1024):
         from collections import OrderedDict
 
         self.capacity = capacity
         self._entries: "OrderedDict" = OrderedDict()
+        self._a2a: Dict = {}
         self.hits = 0
         self.misses = 0
         self.evictions = 0
@@ -875,15 +883,22 @@ class ExecutableCache:
         self.hits += 1
         return exe
 
-    def put(self, sig, exe) -> None:
+    def put(self, sig, exe, a2a_bytes: int = 0) -> None:
         self._entries[sig] = exe
+        self._a2a[sig] = a2a_bytes
         self._entries.move_to_end(sig)
         while len(self._entries) > self.capacity:
-            self._entries.popitem(last=False)
+            old, _ = self._entries.popitem(last=False)
+            self._a2a.pop(old, None)
             self.evictions += 1
+
+    def a2a_bytes(self, sig) -> int:
+        """The ``all_to_all`` bytes one launch of ``sig``'s executable sends."""
+        return self._a2a.get(sig, 0)
 
     def clear(self) -> None:
         self._entries.clear()
+        self._a2a.clear()
 
     def values(self) -> list:
         """The cached executables, least recently used first."""
@@ -953,6 +968,30 @@ def _host_nbytes(args) -> int:
     """Bytes a launch ships to the device: the host (numpy) operands among
     ``args``; device-resident operands move nothing."""
     return sum(a.nbytes for a in args if isinstance(a, np.ndarray))
+
+
+_TENSOR = re.compile(r"tensor<((?:\d+x)*)[a-z]+(\d+)>")
+
+
+def _all_to_all_bytes(lowered, n_devices: int) -> int:
+    """Bytes one launch of ``lowered`` hands to ``all_to_all``, summed over the
+    ``n_devices`` that run it: every ``stablehlo.all_to_all`` operand of the
+    module, whose shard_map body holds one device's shapes."""
+    from jax.interpreters.mlir import ir
+
+    total = 0
+
+    def visit(op):
+        nonlocal total
+        if op.name == "stablehlo.all_to_all":
+            for v in op.operands:
+                m = _TENSOR.fullmatch(str(v.type))
+                dims = [int(d) for d in m.group(1).split("x") if d]
+                total += math.prod(dims) * -(-int(m.group(2)) // 8)
+        return ir.WalkResult.ADVANCE
+
+    lowered.compiler_ir("stablehlo").operation.walk(visit)
+    return total * n_devices
 
 
 def _pulled(outs, ovf) -> Tuple[int, int]:
@@ -1029,6 +1068,7 @@ class BatchRunStats:
     h2d_bytes: int = 0
     d2h_bytes: int = 0
     host_syncs: int = 0
+    a2a_bytes: int = 0
     lowering_us: float = 0.0
 
 
@@ -1181,10 +1221,14 @@ class DataplaneExecutor:
     _tainted_caps: Optional[set] = None     # keys that saw injected overflow
     _caps_quarantined = 0                   # per-run quarantine count
     _run_fps: Tuple[str, ...] = ()          # per-program data fingerprints
+    #: devices on the exchange axis: a scheduler-only harness has no mesh and
+    #: exchanges nothing
+    p = 1
     # per-run transfer counters and lowering host time (see DataplaneJoinResult)
     _h2d_bytes = 0
     _d2h_bytes = 0
     _host_syncs = 0
+    _a2a_bytes = 0
     _lowering_us = 0.0
 
     def __init__(
@@ -1368,7 +1412,7 @@ class DataplaneExecutor:
         self._phase_us = {"host_prep": 0.0, "compile": 0.0, "launch": 0.0, "sync": 0.0}
         self._round_us = {}
         self._device_rows = {}
-        self._h2d_bytes = self._d2h_bytes = self._host_syncs = 0
+        self._h2d_bytes = self._d2h_bytes = self._host_syncs = self._a2a_bytes = 0
         self._lowering_us = 0.0
         self._deadline = config.deadline if config is not None else None
         self._fault_plan_run = (
@@ -1432,6 +1476,7 @@ class DataplaneExecutor:
             h2d_bytes=self._h2d_bytes,
             d2h_bytes=self._d2h_bytes,
             host_syncs=self._host_syncs,
+            a2a_bytes=self._a2a_bytes,
         )
         results: List[DataplaneJoinResult] = []
         with span("executor.assemble") as sp:
@@ -1478,6 +1523,7 @@ class DataplaneExecutor:
                     h2d_bytes=batch.h2d_bytes,
                     d2h_bytes=batch.d2h_bytes,
                     host_syncs=batch.host_syncs,
+                    a2a_bytes=batch.a2a_bytes,
                 ))
             sp.set(rows=sum(r.count for r in results))
         batch.lowering_us = self._lowering_us + sp.us
@@ -1597,7 +1643,8 @@ class DataplaneExecutor:
 
         return finalize, ovf[:s]
 
-    def _run_buckets(self, round_name: str, items: List[_WorkItem], dispatch):
+    def _run_buckets(self, round_name: str, items: List[_WorkItem], dispatch,
+                     routes: bool = False):
         """The one scheduling + retry harness every lowering rule runs on.
 
         Groups ``items`` by (static key, caps) into geometry buckets, calls
@@ -1611,12 +1658,22 @@ class DataplaneExecutor:
         retry-log entry per (group, pass) carries the union of the group's
         channels, exactly like the per-stage harness it replaces.  With
         ``batch_stages=False`` every item forms a singleton bucket — the
-        unbatched schedule, same code path."""
+        unbatched schedule, same code path.
+
+        A routing round (``routes``: each item's result is ``(rows, counts)``
+        per device) adds the rows each device holds after it to
+        ``device_rows`` and to its span's ``device_rows`` argument."""
         if not items:
             return items
         self._check_deadline(round_name)
         with span("executor.round", round=round_name) as sp:
             self._schedule_round(round_name, items, dispatch)
+            if routes:
+                rows = sum(np.asarray(it.result[1], np.int64) for it in items)
+                self._device_rows[round_name] = (
+                    self._device_rows.get(round_name, 0) + rows
+                )
+                sp.set(device_rows=" ".join(map(str, rows.tolist())))
         self._round_us[round_name] = self._round_us.get(round_name, 0.0) + sp.us
         return items
 
@@ -1686,6 +1743,7 @@ class DataplaneExecutor:
             to_compile: Dict[Tuple, Tuple] = {}
             cache = self.compiled_cache
             executables: Dict[Tuple, object] = {}
+            a2a_of: Dict[Tuple, int] = {}
             with span("executor.host_prep", round=round_name) as sp:
                 for bucket in bucket_list:
                     sig = (
@@ -1701,6 +1759,7 @@ class DataplaneExecutor:
                         exe = cache.get(sig)
                         if exe is not None:
                             executables[sig] = exe
+                            a2a_of[sig] = cache.a2a_bytes(sig)
                     if sig in executables or sig in to_compile:
                         self._jit_hits += 1
                     else:
@@ -1723,7 +1782,10 @@ class DataplaneExecutor:
                     sig, (fn, args) = item
                     if fp is not None:
                         fp.at_compile(round_name)
-                    return sig, fn.lower(*args).compile()
+                    lowered = fn.lower(*args)
+                    # over one device JAX lowers no all_to_all: skip the walk
+                    a2a = _all_to_all_bytes(lowered, self.mesh.size) if self.p > 1 else 0
+                    return sig, lowered.compile(), a2a
 
                 with span("executor.compile", round=round_name) as sp:
                     todo = list(to_compile.items())
@@ -1732,31 +1794,33 @@ class DataplaneExecutor:
 
                         workers = min(len(todo), max(2, os.cpu_count() or 2))
                         with ThreadPoolExecutor(max_workers=workers) as pool:
-                            for sig, comp in pool.map(compile_one, todo):
-                                cache.put(sig, comp)
-                                executables[sig] = comp
+                            compiled = list(pool.map(compile_one, todo))
                     else:
-                        sig, comp = compile_one(todo[0])
-                        cache.put(sig, comp)
+                        compiled = [compile_one(todo[0])]
+                    for sig, comp, a2a in compiled:
+                        cache.put(sig, comp, a2a)
                         executables[sig] = comp
+                        a2a_of[sig] = a2a
                 phase["compile"] = phase.get("compile", 0.0) + sp.us
 
             launched = []
-            h2d = d2h = reads = 0
+            h2d = d2h = reads = a2a = 0
             with span("executor.launch", round=round_name) as sp:
                 for bucket, sig, args, post in prepared:
                     self._check_deadline(round_name)
                     if fp is not None:
                         fp.at_dispatch(round_name)
                     h2d += _host_nbytes(args)
+                    a2a += a2a_of[sig]
                     outs = executables[sig](*args)
                     finalize, ovf = post(outs)
                     nbytes, n = _pulled(outs, ovf)
                     d2h, reads = d2h + nbytes, reads + n
                     launched.append((bucket, finalize, ovf))
-                sp.set(h2d_bytes=h2d)
+                sp.set(h2d_bytes=h2d, a2a_bytes=a2a)
             phase["launch"] = phase.get("launch", 0.0) + sp.us
             self._h2d_bytes += h2d
+            self._a2a_bytes += a2a
 
             # one deferred readback per (op, bucket): the scheduler's only
             # host sync — every bucket's collectives are already in flight.
@@ -2340,19 +2404,16 @@ class DataplaneExecutor:
                 floor={"slot": 16, "out": 16},
             )
 
-        for it in self._run_buckets(op.round, items, make_dispatch(count=False)):
+        for it in self._run_buckets(
+            op.round, items, make_dispatch(count=False), routes=True
+        ):
             rows, cnts = it.result
             n = int(cnts.sum())
-            self._note_device_rows(op.round, cnts)
             if it.key[0] == "hc":
                 scheme = ["#cell"] + list(it.payload["scheme"])
             else:
                 scheme = ["#cell", it.payload["x"]]
             it.state.routed[it.payload["pos"]] = (scheme, rows, cnts, n)
-
-    def _note_device_rows(self, round_name: str, cnts) -> None:
-        rows = np.asarray(cnts, np.int64)
-        self._device_rows[round_name] = self._device_rows.get(round_name, 0) + rows
 
     def _make_colocated_dispatch(self, count: bool):
         """Bucket dispatch for one level of in-cell colocated joins — shared
@@ -2807,10 +2868,11 @@ class DataplaneExecutor:
                 floor={"slot": 16, "out": 16},
             )
 
-        for it in self._run_buckets(op.round, items, make_dispatch(count=False)):
+        for it in self._run_buckets(
+            op.round, items, make_dispatch(count=False), routes=True
+        ):
             rows, cnts = it.result
             n = int(cnts.sum())
-            self._note_device_rows(op.round, cnts)
             scheme = ["#cell"] + list(it.payload["scheme"])
             it.state.routed[it.payload["pos"]] = (scheme, rows, cnts, n)
 

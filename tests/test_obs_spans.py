@@ -67,13 +67,13 @@ class Staged(DataplaneExecutor):
 
     staged_bytes = 0
 
-    def _run_buckets(self, round_name, items, dispatch):
+    def _run_buckets(self, round_name, items, dispatch, **kwargs):
         def recording(bucket):
             fn, args, post = dispatch(bucket)
             Staged.staged_bytes += sum(a.nbytes for a in args if isinstance(a, np.ndarray))
             return fn, args, post
 
-        return super()._run_buckets(round_name, items, recording)
+        return super()._run_buckets(round_name, items, recording, **kwargs)
 
 
 def answers(session, q, g):
